@@ -74,6 +74,8 @@ def main() -> None:
     args = ap.parse_args()
 
     sys.path.insert(0, "src")
+    from repro.runtime.compile_cache import enable_compile_cache
+    enable_compile_cache()
     suites = []
     if args.suite in ("all", "sim") and not args.smoke:
         from benchmarks import paper_sim

@@ -27,8 +27,8 @@ def _kernel(q_ref, k_ref, v_ref, o_ref, *, bq: int, bk: int, sk: int,
 
     def body(kv_i, carry):
         m, l, acc = carry
-        k = pl.load(k_ref, (pl.dslice(kv_i * bk, bk), slice(None)))
-        v = pl.load(v_ref, (pl.dslice(kv_i * bk, bk), slice(None)))
+        k = k_ref[pl.ds(kv_i * bk, bk), :]
+        v = v_ref[pl.ds(kv_i * bk, bk), :]
         logits = jnp.dot(q, k.astype(jnp.float32).T,
                          preferred_element_type=jnp.float32)   # [bq, bk]
         q_pos = q_offset + qi * bq + jax.lax.broadcasted_iota(
@@ -56,7 +56,7 @@ def _kernel(q_ref, k_ref, v_ref, o_ref, *, bq: int, bk: int, sk: int,
 
 
 def flash_attention_pallas(q, k, v, *, causal=True, window=None, q_offset=0,
-                           bq=128, bk=128, interpret=True):
+                           bq=128, bk=128, interpret=False):
     """q [B,H,Sq,hd]; k,v [B,H,Sk,hd] (kv heads pre-repeated).  -> [B,H,Sq,hd]"""
     b, h, sq, hd = q.shape
     sk = k.shape[2]

@@ -50,7 +50,7 @@ def _kernel(xd_ref, la_ref, b_ref, c_ref, h0_ref, y_ref, hT_ref, h_scr,
 
 
 def mamba2_pallas(x, dt, a, bm, c, d, h0=None, chunk: int = 128,
-                  interpret=True):
+                  interpret=False):
     b, h, t, p = x.shape
     n = bm.shape[-1]
     q = min(chunk, t)

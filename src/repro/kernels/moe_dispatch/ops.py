@@ -1,20 +1,17 @@
-"""Dispatch wrappers + custom VJPs for the fused MoE dispatch/combine family.
+"""Dispatch wrappers + custom VJPs for the MoE rank/scatter/gather family.
 
 Impl resolution mirrors ``moe_gating``: ``pallas`` on TPU, the vectorized
-jnp implementation of the same fused algorithm (``ref.py``) elsewhere —
-Pallas interpret mode stays available (``impl="interpret"``) for validating
-the kernel itself on CPU, but is a debugging mode, not a fast path.
+jnp implementation of the same algorithm (``ref.py``) elsewhere — Pallas
+interpret mode stays available (``impl="interpret"``) for validating the
+kernels themselves on CPU, but is a debugging mode, not a fast path.
 
-Gradients: the routing decisions (slot, rank, keep, counts) are integers
+Gradients: the routing decisions (rank, keep, counts, ``dest``) are integers
 and carry no gradient; the differentiable dataflow is the weighted scatter
-(dispatch) and weighted gather (combine).  The two are transposes of each
-other, so each one's VJP is the other kernel re-applied:
+(dispatch) and the weighted gather (combine).  The two are transposes of
+each other, so each one's VJP is the other re-applied at the same ``dest``:
 
-* ``d dispatch / d v``  = a combine of the buffer cotangent at the same
-  (slot, rank, keep) — the "combine re-gather".
-* ``d combine / d buf`` = a dispatch of the output cotangent; the rank is
-  recomputed from the identical (slot, valid, cap) inputs, so the scatter
-  lands in exactly the forward buckets.
+* ``d scatter / d v``  = a gather of the buffer cotangent;
+* ``d gather / d buf`` = a scatter of the output cotangent;
 * ``d / d w`` (per-assignment weight) is a row-wise dot of the cotangent
   with the gathered counterpart rows.
 """
@@ -26,19 +23,11 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
-from repro.kernels.moe_dispatch.moe_dispatch import (combine_pallas,
-                                                     dispatch_pallas)
-from repro.kernels.moe_dispatch.ref import combine_ref, dispatch_ref
-
-
-def block_rows(t: int, cap: int = 256) -> int:
-    """Largest divisor of ``t`` that is <= cap (the kernels need
-    t % bt == 0; gcd with a power of two collapses to 1-row blocks for
-    odd t)."""
-    for d in range(min(cap, t), 0, -1):
-        if t % d == 0:
-            return d
-    return 1
+from repro.kernels.moe_dispatch.moe_dispatch import (gather_pallas,
+                                                     rank_pallas,
+                                                     scatter_pallas)
+from repro.kernels.moe_dispatch.ref import gather_ref, rank_ref, scatter_ref
+from repro.kernels.tiling import block_rows
 
 
 def _resolve(impl: str) -> str:
@@ -47,20 +36,28 @@ def _resolve(impl: str) -> str:
     return impl
 
 
-def _dispatch_raw(v, w, slot, valid, n_slots, cap, impl, bt):
+def _rank(slot, valid, n_slots, cap, impl):
     impl = _resolve(impl)
     if impl == "jnp":
-        return dispatch_ref(v, w, slot, valid, n_slots, cap)
-    return dispatch_pallas(v, w, slot, valid, n_slots, cap, bt=bt,
-                           interpret=(impl == "interpret"))
+        return rank_ref(slot, valid, n_slots, cap)
+    return rank_pallas(slot, valid, n_slots, cap, block_rows(len(slot)),
+                       interpret=(impl == "interpret"))
 
 
-def _combine_raw(buf, w, slot, rank, keep, impl, bt):
+def _scatter_raw(v, w, dest, rows, impl):
     impl = _resolve(impl)
     if impl == "jnp":
-        return combine_ref(buf, w, slot, rank, keep)
-    return combine_pallas(buf, w, slot, rank, keep, bt=bt,
+        return scatter_ref(v, w, dest, rows)
+    return scatter_pallas(v, w, dest, rows, block_rows(len(v)),
                           interpret=(impl == "interpret"))
+
+
+def _gather_raw(buf, w, dest, impl):
+    impl = _resolve(impl)
+    if impl == "jnp":
+        return gather_ref(buf, w, dest)
+    return gather_pallas(buf, w, dest, block_rows(len(dest)),
+                         interpret=(impl == "interpret"))
 
 
 def _f0(a):
@@ -68,93 +65,79 @@ def _f0(a):
     return np.zeros(a.shape, jax.dtypes.float0)
 
 
-@partial(jax.custom_vjp, nondiff_argnums=(4, 5, 6, 7))
-def _dispatch_f(v, w, slot, valid, n_slots, cap, impl, bt):
-    """Fused rank + capacity + bucketed scatter.  v [T,D]; w/slot/valid
-    [T,k] -> (buf [S,C,D], rank [T,k], keep [T,k], routed [S], kept [S]).
-
-    The integer routing outputs are returned as f32: a custom_vjp's int
-    outputs carry instantiated float0 tangents that break downstream JVP
-    rules inside a differentiated ``lax.scan`` (the layer stack), while f32
-    outputs get ordinary zero tangents.  ``dispatch`` casts them back."""
-    buf, rank, keep, routed, kept = _dispatch_raw(v, w, slot, valid,
-                                                  n_slots, cap, impl, bt)
-    f = jnp.float32
-    return (buf, rank.astype(f), keep.astype(f), routed.astype(f),
-            kept.astype(f))
+def _row_dot(rows, other, dest):
+    """dw[t, j] = <rows[t, j], other[t]> over kept assignments."""
+    t, k = dest.shape
+    rows = rows.reshape(t, k, -1).astype(jnp.float32)
+    return (rows * other[:, None, :].astype(jnp.float32)).sum(-1) * (dest >= 0)
 
 
-def _dispatch_fwd(v, w, slot, valid, n_slots, cap, impl, bt):
-    buf, rank, keep, routed, kept = _dispatch_raw(v, w, slot, valid,
-                                                  n_slots, cap, impl, bt)
-    f = jnp.float32
-    out = (buf, rank.astype(f), keep.astype(f), routed.astype(f),
-           kept.astype(f))
-    return out, (v, w, slot, valid, rank, keep)
+def _rows_at(buf, dest):
+    return buf[jnp.maximum(dest, 0).reshape(-1)]
 
 
-def _dispatch_bwd(n_slots, cap, impl, bt, res, g):
-    v, w, slot, valid, rank, keep = res
-    g_buf = g[0]                    # integer outputs carry no cotangent
-    dv = _combine_raw(g_buf, w, slot, rank, keep, impl, bt)
-    t, k = slot.shape
-    kb = keep != 0
-    dest = jnp.where(kb, slot * cap + rank, 0).reshape(t * k)
-    rows = g_buf.reshape(n_slots * cap, -1)[dest].reshape(t, k, -1)
-    dw = (rows.astype(jnp.float32) *
-          v[:, None, :].astype(jnp.float32)).sum(-1) * kb
-    return dv.astype(v.dtype), dw.astype(w.dtype), _f0(slot), _f0(valid)
+@partial(jax.custom_vjp, nondiff_argnums=(3, 4))
+def scatter(v, w, dest, rows, impl):
+    """Bucketed scatter: v [T,D]; w [T,k]; dest [T,k] -> buf [rows, D]."""
+    return _scatter_raw(v, w, dest, rows, impl)
 
 
-_dispatch_f.defvjp(_dispatch_fwd, _dispatch_bwd)
+def _scatter_fwd(v, w, dest, rows, impl):
+    return _scatter_raw(v, w, dest, rows, impl), (v, w, dest)
 
 
-def dispatch(v, w, slot, valid, n_slots, cap, impl, bt):
-    """Public fused dispatch; routing outputs as int32 (rank/keep [T,k],
-    routed/kept [S]).  Counts round-trip through f32 (see ``_dispatch_f``),
-    exact for T*k < 2**24."""
-    assert v.shape[0] * slot.shape[1] < 2 ** 24
-    buf, rank, keep, routed, kept = _dispatch_f(v, w, slot, valid, n_slots,
-                                                cap, impl, bt)
-    i = jnp.int32
-    return (buf, rank.astype(i), keep.astype(i), routed.astype(i),
-            kept.astype(i))
+def _scatter_bwd(rows, impl, res, g_buf):
+    v, w, dest = res
+    dv = _gather_raw(g_buf, w, dest, impl)
+    dw = _row_dot(_rows_at(g_buf, dest), v, dest)
+    return dv.astype(v.dtype), dw.astype(w.dtype), _f0(dest)
 
 
-@partial(jax.custom_vjp, nondiff_argnums=(6, 7))
-def combine(buf, w, slot, rank, keep, valid, impl, bt):
-    """Weighted gather back to token rows.  ``valid`` is only consumed by
-    the VJP (it lets the backward scatter recompute the forward ranks)."""
-    return _combine_raw(buf, w, slot, rank, keep, impl, bt)
+scatter.defvjp(_scatter_fwd, _scatter_bwd)
 
 
-def _combine_fwd(buf, w, slot, rank, keep, valid, impl, bt):
-    y = _combine_raw(buf, w, slot, rank, keep, impl, bt)
-    return y, (buf, w, slot, rank, keep, valid)
+@partial(jax.custom_vjp, nondiff_argnums=(3,))
+def gather(buf, w, dest, impl):
+    """Weighted gather: buf [rows, D]; w [T,k]; dest [T,k] -> y [T, D]."""
+    return _gather_raw(buf, w, dest, impl)
 
 
-def _combine_bwd(impl, bt, res, g_y):
-    buf, w, slot, rank, keep, valid = res
+def _gather_fwd(buf, w, dest, impl):
+    return _gather_raw(buf, w, dest, impl), (buf, w, dest)
+
+
+def _gather_bwd(impl, res, g_y):
+    buf, w, dest = res
+    d_buf = _scatter_raw(g_y, w, dest, buf.shape[0], impl)
+    dw = _row_dot(_rows_at(buf, dest), g_y, dest)
+    return d_buf.astype(buf.dtype), dw.astype(w.dtype), _f0(dest)
+
+
+gather.defvjp(_gather_fwd, _gather_bwd)
+
+
+def _dest(slot, rank, keep, cap):
+    return jnp.where(keep != 0, slot * cap + rank, -1)
+
+
+def dispatch(v, w, slot, valid, n_slots, cap, impl):
+    """Rank + capacity mask + bucketed scatter.  v [T,D]; w/slot/valid
+    [T,k] -> (buf [S,C,D], rank [T,k], keep [T,k], routed [S], kept [S])."""
+    rank, keep, routed, kept = _rank(slot, valid, n_slots, cap, impl)
+    buf = scatter(v, w, _dest(slot, rank, keep, cap), n_slots * cap, impl)
+    return buf.reshape(n_slots, cap, -1), rank, keep, routed, kept
+
+
+def combine(buf, w, slot, rank, keep, impl):
+    """Weighted gather of buf [S,C,D] back to token rows -> y [T,D]."""
     s, cap, d = buf.shape
-    # same (slot, valid, cap) => the dispatch recomputes the identical
-    # rank/keep, so the cotangent scatter fills exactly the forward buckets
-    d_buf = _dispatch_raw(g_y, w, slot, valid, s, cap, impl, bt)[0]
-    t, k = slot.shape
-    kb = keep != 0
-    dest = jnp.where(kb, slot * cap + rank, 0).reshape(t * k)
-    rows = buf.reshape(s * cap, d)[dest].reshape(t, k, d)
-    dw = (rows.astype(jnp.float32) *
-          g_y[:, None, :].astype(jnp.float32)).sum(-1) * kb
-    return (d_buf.astype(buf.dtype), dw.astype(w.dtype), _f0(slot),
-            _f0(rank), _f0(keep), _f0(valid))
-
-
-combine.defvjp(_combine_fwd, _combine_bwd)
+    return gather(buf.reshape(s * cap, d), w, _dest(slot, rank, keep, cap),
+                  impl)
 
 
 def dispatch_combine(x, slot, weight, expert_fn, n_slots: int, cap: int,
-                     valid=None, impl: str = "auto", bt: int = 0):
-    """Drop-in for ``models.moe.dispatch_combine`` on the fused kernels.
+                     valid=None, impl: str = "auto"):
+    """Drop-in for ``models.moe.dispatch_combine`` on the kernel family.
 
     Returns (y [T,D], metrics) with bit-identical token-drop decisions and
     Reshape load metrics (slot_counts = routed phi, kept_counts, dropped)
@@ -164,13 +147,11 @@ def dispatch_combine(x, slot, weight, expert_fn, n_slots: int, cap: int,
     k = slot.shape[1]
     valid_i = (jnp.ones((t, k), jnp.int32) if valid is None
                else valid.astype(jnp.int32))
-    bt = bt or block_rows(t)
     ones = jnp.ones((t, k), jnp.float32)
     buf, rank, keep, routed, kept = dispatch(x, ones, slot, valid_i,
-                                             n_slots, cap, impl, bt)
+                                             n_slots, cap, impl)
     out_buf = expert_fn(buf)
-    y = combine(out_buf, weight.astype(jnp.float32), slot, rank, keep,
-                valid_i, impl, bt)
+    y = combine(out_buf, weight.astype(jnp.float32), slot, rank, keep, impl)
     dropped = valid_i.sum() - keep.sum()
     return y.astype(x.dtype), {"slot_counts": routed, "kept_counts": kept,
                                "dropped": dropped}

@@ -32,7 +32,10 @@ def _kernel(logits_ref, w_ref, e_ref, cnt_ref, *, k: int, bt: int, e: int):
     ws, es, hist = [], [], jnp.zeros((e,), jnp.int32)
     for _ in range(k):
         m = remaining.max(-1)
-        idx = jnp.argmax(remaining, -1).astype(jnp.int32)
+        # lowest index among equal probabilities, as lax.top_k breaks ties:
+        # bf16 router logits tie often, and a different pick changes the
+        # routing, hence the drops.  argmax's tie order is not pinned on TPU.
+        idx = jnp.where(remaining == m[:, None], iota_e, e).min(-1)
         onehot = (iota_e == idx[:, None])
         remaining = jnp.where(onehot, -1.0, remaining)
         ws.append(m)
@@ -45,7 +48,7 @@ def _kernel(logits_ref, w_ref, e_ref, cnt_ref, *, k: int, bt: int, e: int):
     cnt_ref[...] += hist
 
 
-def gating_pallas(logits, k: int, bt: int = 256, interpret=True):
+def gating_pallas(logits, k: int, bt: int = 256, interpret=False):
     """logits [T,E] -> (weights [T,k] f32, experts [T,k] i32, counts [E] i32)."""
     t, e = logits.shape
     bt = min(bt, t)

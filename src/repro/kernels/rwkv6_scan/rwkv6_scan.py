@@ -56,7 +56,7 @@ def _kernel(r_ref, k_ref, v_ref, w_ref, u_ref, s0_ref, y_ref, sT_ref, s_scr,
         sT_ref[...] = s_new
 
 
-def rwkv6_pallas(r, k, v, w, u, s0=None, chunk: int = 64, interpret=True):
+def rwkv6_pallas(r, k, v, w, u, s0=None, chunk: int = 64, interpret=False):
     """r,k,v,w [B,H,T,N]; u [H,N]; s0 [B,H,N,N] -> (y, sT)."""
     b, h, t, n = r.shape
     q = min(chunk, t)

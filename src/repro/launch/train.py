@@ -15,6 +15,42 @@ import json
 import time
 
 
+def build_loop(cfg, *, steps: int, seq_len: int, global_batch: int,
+               microbatches: int = 2, lr: float = 3e-4,
+               reshape: bool = False, class_alpha: float = 1.5,
+               ckpt_dir: str = "", ckpt_every: int = 0,
+               resume: bool = False, ep_ranks: int = 2,
+               dispatch_select: str = "off"):
+    """The TrainLoop this launcher runs: synthetic Zipf-skewed token
+    stream, AdamW with warmup, optional Reshape expert-skew mitigation and
+    periodic checkpoints (``resume`` recovers from ``ckpt_dir``)."""
+    from repro.core.reshape_moe import MoEReshaper
+    from repro.core.skew import SkewParams
+    from repro.data.synthetic import TokenStream
+    from repro.models import lm
+    from repro.optim.adamw import AdamWCfg
+    from repro.runtime.loop import LoopConfig, TrainLoop
+    from repro.runtime.train import TrainHyper
+
+    stream = TokenStream(vocab=cfg.vocab, seq_len=seq_len,
+                         global_batch=global_batch, seed=0,
+                         class_alpha=class_alpha)
+    hyper = TrainHyper(opt=AdamWCfg(lr=lr, warmup_steps=20,
+                                    total_steps=max(steps, 100)))
+    lc = LoopConfig(microbatches=microbatches, ckpt_every=ckpt_every,
+                    ckpt_dir=ckpt_dir or "/tmp/repro_train_ckpt",
+                    dispatch_select=dispatch_select)
+    reshaper = None
+    if reshape and lm.n_moe_layers(cfg):
+        reshaper = MoEReshaper(cfg, lm.n_moe_layers(cfg), ep_ranks=ep_ranks,
+                               params=SkewParams(eta=0.0, tau=0.2))
+    if resume:
+        loop = TrainLoop.recover(cfg, stream, hyper, lc, reshaper=reshaper)
+        print(f"recovered at step {int(loop.state['step'])}")
+        return loop
+    return TrainLoop(cfg, stream, hyper, lc, reshaper=reshaper)
+
+
 def main():
     ap = argparse.ArgumentParser()
     ap.add_argument("--arch", default="paper-moe-100m-smoke")
@@ -33,34 +69,17 @@ def main():
     ap.add_argument("--ep-ranks", type=int, default=2)
     args = ap.parse_args()
 
+    from repro.runtime.compile_cache import enable_compile_cache
+    enable_compile_cache()
     from repro.configs import get_arch
-    from repro.core.reshape_moe import MoEReshaper
-    from repro.core.skew import SkewParams
-    from repro.data.synthetic import TokenStream
-    from repro.models import lm
-    from repro.optim.adamw import AdamWCfg
-    from repro.runtime.loop import LoopConfig, TrainLoop
-    from repro.runtime.train import TrainHyper
 
-    cfg = get_arch(args.arch)
-    stream = TokenStream(vocab=cfg.vocab, seq_len=args.seq_len,
-                         global_batch=args.global_batch, seed=0,
-                         class_alpha=args.class_alpha)
-    hyper = TrainHyper(opt=AdamWCfg(lr=args.lr, warmup_steps=20,
-                                    total_steps=max(args.steps, 100)))
-    lc = LoopConfig(microbatches=args.microbatches,
-                    ckpt_every=args.ckpt_every,
-                    ckpt_dir=args.ckpt_dir or "/tmp/repro_train_ckpt")
-    reshaper = None
-    if args.reshape and lm.n_moe_layers(cfg):
-        reshaper = MoEReshaper(cfg, lm.n_moe_layers(cfg),
-                               ep_ranks=args.ep_ranks,
-                               params=SkewParams(eta=0.0, tau=0.2))
-    if args.resume:
-        loop = TrainLoop.recover(cfg, stream, hyper, lc, reshaper=reshaper)
-        print(f"recovered at step {int(loop.state['step'])}")
-    else:
-        loop = TrainLoop(cfg, stream, hyper, lc, reshaper=reshaper)
+    loop = build_loop(get_arch(args.arch), steps=args.steps,
+                      seq_len=args.seq_len, global_batch=args.global_batch,
+                      microbatches=args.microbatches, lr=args.lr,
+                      reshape=args.reshape, class_alpha=args.class_alpha,
+                      ckpt_dir=args.ckpt_dir, ckpt_every=args.ckpt_every,
+                      resume=args.resume, ep_ranks=args.ep_ranks)
+    reshaper = loop.reshaper
     t0 = time.perf_counter()
     hist = loop.run(args.steps)
     dt = time.perf_counter() - t0

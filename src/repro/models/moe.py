@@ -54,16 +54,6 @@ def capacity(cfg: ArchConfig, tokens: int) -> int:
     return max(4, int(tokens * m.top_k * m.capacity_factor / m.num_experts))
 
 
-def _gating_block(t: int, cap: int = 256) -> int:
-    """Largest divisor of ``t`` that is <= cap (gating_pallas needs
-    t % bt == 0; gcd(t, 256) only yields powers of two and collapses to a
-    1-row block for odd t)."""
-    for d in range(min(cap, t), 0, -1):
-        if t % d == 0:
-            return d
-    return 1
-
-
 def _hash_unit(idx):
     """Deterministic token -> [0,1) bucket (Knuth multiplicative hash)."""
     h = (idx.astype(jnp.uint32) * jnp.uint32(2654435761))
@@ -86,9 +76,8 @@ def route(router_w, x, plan_slots, plan_cum, cfg: ArchConfig, token_offset=0):
         # kernel itself needs no VJP rule.
         from repro.kernels.moe_gating.ops import gating
         impl = "pallas" if jax.default_backend() == "tpu" else "interpret"
-        bt = _gating_block(x.shape[0])
         _, top_e, counts = gating(jax.lax.stop_gradient(logits), m.top_k,
-                                  impl=impl, bt=bt)
+                                  impl=impl)
         top_p = jnp.take_along_axis(probs, top_e, axis=-1)  # [T,k]
     else:
         top_p, top_e = jax.lax.top_k(probs, m.top_k)        # [T,k]
@@ -112,10 +101,11 @@ def dispatch_combine(x, slot, weight, expert_fn, n_slots: int, cap: int,
     this shard (EP: foreign experts are some other rank's problem, not
     drops).  Returns (y [T,D], metrics dict).
 
-    ``fused=True`` routes through the fused Pallas dispatch/combine kernel
-    family (``kernels/moe_dispatch``): rank + capacity mask + bucketed
-    scatter in one kernel instead of the argsort/searchsorted/scatter
-    round-trip below, with bit-identical drop decisions and load metrics.
+    ``fused=True`` routes through the Pallas dispatch/combine kernel
+    family (``kernels/moe_dispatch``): a sort-free rank + capacity mask
+    kernel and one-hot-matmul scatter/gather kernels instead of the
+    argsort/searchsorted/scatter round-trip below, with bit-identical drop
+    decisions and load metrics.
     """
     if fused:
         from repro.kernels.moe_dispatch.ops import \
@@ -301,11 +291,10 @@ def moe_ffn_a2a(p, x, plan_slots, plan_cum, cfg: ArchConfig, mesh,
         cap_s = max(4, int(tk * m_cfg.capacity_factor / mdl))
         if m_cfg.fused_dispatch:
             from repro.kernels.moe_dispatch import ops as _dops
-            all_valid = jnp.ones((t_loc, m_cfg.top_k), jnp.int32)
-            bt = _dops.block_rows(t_loc)
             send_x3, rank2, keep2, _, _ = _dops.dispatch(
                 xl, jnp.ones((t_loc, m_cfg.top_k), jnp.float32), col_of,
-                all_valid, mdl, cap_s, "auto", bt)
+                jnp.ones((t_loc, m_cfg.top_k), jnp.int32), mdl, cap_s,
+                "auto")
             pos = rank2.reshape(tk)
             keep = keep2.reshape(tk) != 0
             dest = jnp.where(keep, flat_col * cap_s + pos, mdl * cap_s)
@@ -351,7 +340,7 @@ def moe_ffn_a2a(p, x, plan_slots, plan_cum, cfg: ArchConfig, mesh,
                                     split_axis=0, concat_axis=0, tiled=False)
         if m_cfg.fused_dispatch:
             y = _dops.combine(y_back, weight.astype(jnp.float32), col_of,
-                              rank2, keep2, all_valid, "auto", bt)
+                              rank2, keep2, "auto")
             y = y.astype(xl.dtype)
         else:
             y_back = y_back.reshape(mdl * cap_s, d)

@@ -28,7 +28,7 @@ def test_flash_attention_vs_ref(shape, causal, window, dtype):
     b, h, s, d = shape
     q, k, v = (randn(b, h, s, d).astype(dtype) for _ in range(3))
     out = flash_attention_pallas(q, k, v, causal=causal, window=window,
-                                 bq=128, bk=128)
+                                 bq=128, bk=128, interpret=True)
     ref = attention_ref(q, k, v, causal=causal, window=window)
     tol = 2e-2 if dtype == jnp.bfloat16 else 2e-3
     np.testing.assert_allclose(np.asarray(out, np.float32),
@@ -54,17 +54,20 @@ def test_chunked_jnp_matches_ref():
 # -------------------------------------------------------------------- gating
 
 @pytest.mark.parametrize("t,e,k", [(256, 16, 4), (512, 64, 8), (128, 8, 2)])
-def test_gating_kernel(t, e, k):
+@pytest.mark.parametrize("ties", [False, True], ids=["distinct", "ties"])
+def test_gating_kernel(t, e, k, ties):
     from repro.kernels.moe_gating.moe_gating import gating_pallas
     from repro.kernels.moe_gating.ref import gating_ref
     logits = randn(t, e)
-    w1, e1, c1 = gating_pallas(logits, k, bt=128)
+    if ties:                # coarse logits: equal values in most rows
+        logits = np.round(logits * 2) / 2
+    w1, e1, c1 = gating_pallas(logits, k, bt=128, interpret=True)
     w2, e2, c2 = gating_ref(logits, k)
     np.testing.assert_array_equal(np.asarray(c1), np.asarray(c2))
     np.testing.assert_allclose(np.sort(np.asarray(w1), -1),
                                np.sort(np.asarray(w2), -1), atol=1e-5,
                                rtol=1e-4)
-    # same expert sets per row
+    # same experts per row, ties going to the lower index as in lax.top_k
     np.testing.assert_array_equal(np.sort(np.asarray(e1), -1),
                                   np.sort(np.asarray(e2), -1))
 
@@ -88,7 +91,7 @@ def test_rwkv6_chunked_and_pallas(b, h, t, n, chunk):
                                rtol=2e-2)
     np.testing.assert_allclose(np.asarray(sT1), np.asarray(sT0), atol=2e-3,
                                rtol=2e-2)
-    y2, sT2 = rwkv6_pallas(r, k, v, w, u, s0, chunk=chunk)
+    y2, sT2 = rwkv6_pallas(r, k, v, w, u, s0, chunk=chunk, interpret=True)
     np.testing.assert_allclose(np.asarray(y2), np.asarray(y0), atol=2e-3,
                                rtol=2e-2)
     np.testing.assert_allclose(np.asarray(sT2), np.asarray(sT0), atol=2e-3,
@@ -132,7 +135,8 @@ def test_mamba2_chunked_and_pallas(b, h, t, p, n, chunk):
     y1, hT1 = mamba2_chunked(x, dt, a, bm, c, d, h0, chunk=chunk)
     np.testing.assert_allclose(np.asarray(y1), np.asarray(y0), atol=1e-3,
                                rtol=1e-2)
-    y2, hT2 = mamba2_pallas(x, dt, a, bm, c, d, h0, chunk=chunk)
+    y2, hT2 = mamba2_pallas(x, dt, a, bm, c, d, h0, chunk=chunk,
+                            interpret=True)
     np.testing.assert_allclose(np.asarray(y2), np.asarray(y0), atol=1e-3,
                                rtol=1e-2)
     np.testing.assert_allclose(np.asarray(hT2), np.asarray(hT0), atol=1e-3,
@@ -158,3 +162,17 @@ def test_mamba2_decode_matches_scan():
         ys.append(np.asarray(y))
     np.testing.assert_allclose(np.stack(ys, 2), np.asarray(y_ref), atol=1e-4,
                                rtol=1e-3)
+
+
+# ------------------------------------------------------------ block helper
+
+@pytest.mark.parametrize("t", [1, 7, 8, 37, 64, 150, 300, 512, 4096, 4097,
+                               4104, 8 * 263])
+def test_block_rows_is_mosaic_legal(t):
+    """A multiple of 8 dividing t (the largest <= 256), or t itself when
+    no multiple of 8 divides it."""
+    from repro.kernels.tiling import block_rows
+    bt = block_rows(t)
+    assert t % bt == 0
+    aligned = [d for d in range(8, min(256, t) + 1, 8) if t % d == 0]
+    assert bt == (max(aligned) if aligned else t)

@@ -17,9 +17,10 @@ import pytest
 
 from repro.configs import get_arch
 from repro.kernels.moe_dispatch import ops as dops
-from repro.kernels.moe_dispatch.moe_dispatch import (combine_pallas,
-                                                     dispatch_pallas)
-from repro.kernels.moe_dispatch.ref import combine_ref, dispatch_ref
+from repro.kernels.moe_dispatch.moe_dispatch import (gather_pallas,
+                                                     rank_pallas,
+                                                     scatter_pallas)
+from repro.kernels.moe_dispatch.ref import gather_ref, rank_ref, scatter_ref
 from repro.models import moe as moe_lib
 
 RNG = np.random.default_rng(0)
@@ -71,8 +72,7 @@ def test_capacity_overflow_drop_parity():
     ones_w = jnp.ones((t, k), jnp.float32)
     ones_v = jnp.ones((t, k), jnp.int32)
     _, rank, keep, routed, kept = dops.dispatch(x, ones_w, slot, ones_v, s,
-                                                cap, "jnp",
-                                                dops.block_rows(t))
+                                                cap, "jnp")
     # reference ranks via the baseline's stable argsort
     flat = np.asarray(slot).reshape(-1)
     sort_idx = np.argsort(flat, kind="stable")
@@ -85,22 +85,28 @@ def test_capacity_overflow_drop_parity():
     assert int(kept.sum()) < int(routed.sum())   # overflow really happened
 
 
-def test_pallas_interpret_matches_ref_raw():
+# cap=9 drops assignments; cap=40 gives 320 buffer rows, more than one
+# 256-row tile and not a multiple of it (the last tile is padded)
+@pytest.mark.parametrize("cap", [9, 40])
+def test_pallas_interpret_matches_ref_raw(cap):
     """The Pallas kernels (interpret mode) against the jnp oracle at the
-    raw dispatch/combine level, including the weighted-scatter operand."""
-    t, d, k, s, cap = 64, 16, 3, 8, 9
+    raw rank/scatter/gather level, including the weighted-scatter operand."""
+    t, d, k, s = 64, 16, 3, 8
     x, slot, wgt, _ = _case(t, d, k, s, skew=True)
     w = jnp.asarray(RNG.uniform(0.5, 2.0, (t, k)), jnp.float32)
     valid = jnp.asarray(RNG.random((t, k)) < 0.8).astype(jnp.int32)
-    r0 = dispatch_ref(x, w, slot, valid, s, cap)
-    r1 = dispatch_pallas(x, w, slot, valid, s, cap, bt=16)
-    np.testing.assert_allclose(np.asarray(r0[0]), np.asarray(r1[0]),
-                               atol=1e-5, rtol=1e-5)          # buf
-    for a, b in zip(r0[1:], r1[1:]):                          # int outputs
+    r0 = rank_ref(slot, valid, s, cap)
+    r1 = rank_pallas(slot, valid, s, cap, bt=16, interpret=True)
+    for a, b in zip(r0, r1):                                  # int outputs
         np.testing.assert_array_equal(np.asarray(a), np.asarray(b))
-    buf, rank, keep = r0[0], r0[1], r0[2]
-    y0 = combine_ref(buf, wgt, slot, rank, keep)
-    y1 = combine_pallas(buf, wgt, slot, rank, keep, bt=16)
+    rank, keep = r0[0], r0[1]
+    dest = jnp.where(keep != 0, slot * cap + rank, -1)
+    b0 = scatter_ref(x, w, dest, s * cap)
+    b1 = scatter_pallas(x, w, dest, s * cap, bt=16, interpret=True)
+    np.testing.assert_allclose(np.asarray(b0), np.asarray(b1),
+                               atol=1e-5, rtol=1e-5)          # buf
+    y0 = gather_ref(b0, wgt, dest)
+    y1 = gather_pallas(b0, wgt, dest, bt=16, interpret=True)
     np.testing.assert_allclose(np.asarray(y0), np.asarray(y1),
                                atol=1e-5, rtol=1e-5)
 
