@@ -38,7 +38,6 @@ engine their inspect callback and their job thunks and let it decide.
 """
 from __future__ import annotations
 
-import time
 from collections import deque
 from typing import Any, Callable, Deque, Dict, List, Optional, Tuple
 
@@ -50,6 +49,7 @@ from repro.core.scheduler import (CostModel, compare_frt, completion_time,
                                   placement_adjusted_frt,
                                   weighted_first_response_time)
 from repro.engine import jobs as J
+from repro.runtime import trace
 
 
 class Engine:
@@ -106,13 +106,14 @@ class Engine:
         """Execute a job thunk, feed its measured runtime back into the cost
         book (per token when the job reports a token count, else per job).
         ``extra`` jobs record the same duration under additional kinds —
-        e.g. a train step also measured as a dispatch-impl sample."""
-        t0 = time.perf_counter()
-        out = fn()
-        dt = time.perf_counter() - t0
-        self.observe(job, dt)
+        e.g. a train step also measured as a dispatch-impl sample.  The
+        thunk runs inside a span named by ``job.kind``, whose duration is
+        the measurement."""
+        with trace.span(job.kind) as s:
+            out = fn()
+        self.observe(job, s.seconds)
         for j in extra:
-            self.observe(j, dt)
+            self.observe(j, s.seconds)
         return out
 
     def observe(self, job: J.Job, seconds: float) -> None:

@@ -156,6 +156,7 @@ from repro.engine.jobs import (COST_DEFAULTS, Job, TickCandidate,
                                layout_kind, pool_kind, spec_kind)
 from repro.engine.prefix_cache import PrefixAnalyzer, PrefixCache, to_host
 from repro.models import lm
+from repro.runtime import trace
 from repro.runtime.sharding import (axis_size, named, param_specs, pool_mesh,
                                     pool_specs)
 
@@ -346,22 +347,36 @@ def build_slot_tick(cfg: ArchConfig, spec_len: int = 0,
     propose = PROPOSERS[proposer].build(cfg, draft_cfg, ng_hash, push) \
         if spec_len else None
 
+    def write_back(active, caches, c2, ng, ng2, ctx, ctx2, draft0, d2):
+        """The slot's pool row after the scan: the new state where the
+        slot was active, the old one where it sat out."""
+        with jax.named_scope("kv_pool"):
+            pool_f = {"caches": jax.tree.map(
+                lambda o, n: jnp.where(active, n, o), caches, c2),
+                "ng": jnp.where(active, ng2, ng),
+                "ctx": jnp.where(active, ctx2, ctx)}
+            if draft_cfg is not None:
+                pool_f["draft"] = jax.tree.map(
+                    lambda o, n: jnp.where(active, n, o), draft0, d2)
+        return pool_f
+
     def one_slot(params, dparams, pool, pos, toks, n_given, active, reset,
                  key, temp):
         caches, ng, ctx = pool["caches"], pool["ng"], pool["ctx"]
         # a freshly joined slot starts from a zeroed cache row, an empty
         # suffix table, zeroed draft state and pos 0 — folded into the tick
         # so the join costs no eager scatter dispatches
-        caches = jax.tree.map(
-            lambda c: jnp.where(reset, jnp.zeros_like(c), c), caches)
-        ng = jnp.where(reset, 0, ng)
-        ctx = jnp.where(reset, 0, ctx)
-        pos = jnp.where(reset, 0, pos)
-        draft0 = None
-        if draft_cfg is not None:
-            draft0 = jax.tree.map(
-                lambda c: jnp.where(reset, jnp.zeros_like(c), c),
-                pool["draft"])
+        with jax.named_scope("kv_pool"):
+            caches = jax.tree.map(
+                lambda c: jnp.where(reset, jnp.zeros_like(c), c), caches)
+            ng = jnp.where(reset, 0, ng)
+            ctx = jnp.where(reset, 0, ctx)
+            pos = jnp.where(reset, 0, pos)
+            draft0 = None
+            if draft_cfg is not None:
+                draft0 = jax.tree.map(
+                    lambda c: jnp.where(reset, jnp.zeros_like(c), c),
+                    pool["draft"])
         L = toks.shape[0]
 
         if spec_len:
@@ -383,7 +398,8 @@ def build_slot_tick(cfg: ArchConfig, spec_len: int = 0,
                 logits, new = lm.decode_step(
                     params, {"caches": caches, "pos": pos}, tok[None, None],
                     cfg)
-                nxt = jnp.argmax(logits[0], -1).astype(jnp.int32)
+                with jax.named_scope("head"):
+                    nxt = jnp.argmax(logits[0], -1).astype(jnp.int32)
                 # freeze only NON-positional state past the first mismatch:
                 # KV rows a rejected step writes sit past the frozen pos —
                 # dead until the next accepted token overwrites them — but
@@ -409,13 +425,8 @@ def build_slot_tick(cfg: ArchConfig, spec_len: int = 0,
             (c2, d2, p2, ng2, ctx2, _), (emitted, valids) = jax.lax.scan(
                 body, (caches, draft0, pos, ng, ctx, jnp.bool_(True)),
                 jnp.arange(L))
-            pool_f = {"caches": jax.tree.map(
-                lambda o, n: jnp.where(active, n, o), caches, c2),
-                "ng": jnp.where(active, ng2, ng),
-                "ctx": jnp.where(active, ctx2, ctx)}
-            if draft_cfg is not None:
-                pool_f["draft"] = jax.tree.map(
-                    lambda o, n: jnp.where(active, n, o), draft0, d2)
+            pool_f = write_back(active, caches, c2, ng, ng2, ctx, ctx2,
+                                draft0, d2)
             n_valid = jnp.where(active, valids.sum(dtype=jnp.int32), 0)
             return (pool_f, jnp.where(active, p2, pos), key, emitted,
                     n_valid)
@@ -434,19 +445,15 @@ def build_slot_tick(cfg: ArchConfig, spec_len: int = 0,
                 # stream whichever arm the engine picks next tick
                 draft = feed_draft(dparams, draft, pos, tok)
             key, sub = jax.random.split(key)
-            nxt = sample_traced(logits[0], sub, temp)
+            with jax.named_scope("head"):
+                nxt = sample_traced(logits[0], sub, temp)
             return (new["caches"], draft, new["pos"], nxt, key, ng, win), nxt
 
         (c2, d2, p2, _, k2, ng2, ctx2), emitted = jax.lax.scan(
             body, (caches, draft0, pos, toks[0], key, ng, ctx),
             jnp.arange(L))
-        pool_f = {"caches": jax.tree.map(
-            lambda o, n: jnp.where(active, n, o), caches, c2),
-            "ng": jnp.where(active, ng2, ng),
-            "ctx": jnp.where(active, ctx2, ctx)}
-        if draft_cfg is not None:
-            pool_f["draft"] = jax.tree.map(
-                lambda o, n: jnp.where(active, n, o), draft0, d2)
+        pool_f = write_back(active, caches, c2, ng, ng2, ctx, ctx2, draft0,
+                            d2)
         return (pool_f, jnp.where(active, p2, pos),
                 jnp.where(active, k2, key), emitted,
                 jnp.where(active, jnp.int32(L), 0))
@@ -525,8 +532,9 @@ class Request:
     # last advanced; the peak is kept for the starvation regression tests
     deferred: int = 0
     max_deferred: int = 0
-    # wall-clock marks for the latency benches (first-token / completion)
+    # wall-clock marks: queued, joined a slot, first token, completion
     t_submit: float = 0.0
+    t_admit: Optional[float] = None
     t_first: Optional[float] = None
     t_done: Optional[float] = None
     done: threading.Event = dataclasses.field(
@@ -790,6 +798,20 @@ class ServeEngine:
         self.queue: Deque[Request] = deque()
         self.tick_no = 0
         self.tokens_out = 0
+        # observability counters (running totals, never reset): host
+        # seconds of the tick's phases and of whole ticks by composition,
+        # and where a request's time to first token goes — the wait for a
+        # slot (submit -> admit) and its prefill (admit -> first token)
+        self.admit_s = 0.0
+        self.plan_s = 0.0
+        self.commit_s = 0.0
+        self.prefill_tick_s = 0.0
+        self.decode_tick_s = 0.0
+        self.queue_wait_s = 0.0
+        self.admitted = 0
+        self.prefill_s = 0.0
+        self.first_tokens = 0
+        self.cache_answered = 0      # result-cache hits: never take a slot
         self._rid = itertools.count()
         self.hit_breakpoints: List[str] = []
         # closed-loop knob tuning (engine.autotune): the meta-controller
@@ -1076,6 +1098,7 @@ class ServeEngine:
         req.t_first = req.t_first or now
         req.t_done = now
         self.tokens_out += len(req.tokens)
+        self.cache_answered += 1
         req.done.set()
 
     def _snapshot_slot(self, sp: SlotPool, slot: int, path) -> None:
@@ -1143,6 +1166,7 @@ class ServeEngine:
         joined: Dict[int, list] = {}
         seeds: Dict[int, list] = {}
         remaining: Deque[Request] = deque()
+        now = time.perf_counter()
         for req in self.queue:
             if (self.prefix is not None and req.temperature <= 0
                     and (out := self.prefix.result_lookup(
@@ -1172,6 +1196,9 @@ class ServeEngine:
             slot = next(s for s in range(sp.slots) if sp.active[s] is None)
             req.pool, req.slot = pid, slot
             req.joined_version = self.params_version
+            req.t_admit = now
+            self.admitted += 1
+            self.queue_wait_s += now - req.t_submit
             sp.active[slot] = req
             node = None
             if self.prefix is not None and req.temperature <= 0:
@@ -1233,6 +1260,12 @@ class ServeEngine:
         info = {"tick": self.tick_no, "queue_depth": len(self.queue),
                 "tokens_out": self.tokens_out,
                 "paused": self.engine.controller.paused,
+                # where the host time and the time to first token went
+                # (running totals; engine README, "Observability")
+                "counters": {k: getattr(self, k) for k in (
+                    "admit_s", "plan_s", "commit_s", "prefill_tick_s",
+                    "decode_tick_s", "queue_wait_s", "admitted",
+                    "prefill_s", "first_tokens", "cache_answered")},
                 "spec": {"enabled": self.spec_decode,
                          "ticks": self.spec_ticks,
                          "proposed": self.spec_proposed,
@@ -1679,6 +1712,8 @@ class ServeEngine:
             outs_r = em[s, g - 1:last][:need]
             if outs_r.size and r.t_first is None:
                 r.t_first = now               # first-token latency mark
+                self.first_tokens += 1
+                self.prefill_s += now - r.t_admit
             r.tokens.extend(int(t) for t in outs_r)
             n_new += len(outs_r)
             if len(r.tokens) >= r.max_new:
@@ -1768,17 +1803,85 @@ class ServeEngine:
         adjusted, + per-class aging bounds) and one pool wins the round —
         then, with device-placed pools, plain decode ticks for the other
         placed pools co-dispatch alongside the winner (``_group_plans``)
-        so disjoint device groups decode concurrently."""
-        if self._poll():
-            return False
-        self._drain_step()
-        self._admit()
+        so disjoint device groups decode concurrently.
+
+        Observability: the tick runs inside a ``serve.tick`` span whose
+        phases are spans too (``serve.control``, ``serve.admit``,
+        ``serve.plan``, the dispatch span ``Engine.run_job`` names by job
+        kind, ``serve.commit``); the phase and composition totals land in
+        the engine's counters (``_inspect()["counters"]``)."""
+        with trace.span("serve.tick") as tick_span:
+            alive, plan = self._run_tick(tick_span)
+        if plan is not None:
+            if plan.mode == "prefill":
+                self.prefill_tick_s += tick_span.seconds
+            else:                         # plain and speculative decode
+                self.decode_tick_s += tick_span.seconds
+        return alive
+
+    def _run_tick(self, tick_span) -> tuple:
+        """The body of ``tick``: returns (not stopped, the winning plan or
+        None for an idle round)."""
+        with trace.span("serve.control"):
+            if self._poll():
+                return False, None
+            self._drain_step()
+        with trace.span("serve.admit", into=(self, "admit_s")):
+            self._admit()
+        with trace.span("serve.plan", into=(self, "plan_s")):
+            decisions = self.engine.decisions
+            mark = decisions[-1] if decisions else None
+            plan = self._choose_plan()
+            group = self._group_plans(plan) if plan is not None else []
+        if plan is None:
+            return True, None
+        tick_span.set(mode=plan.mode, compact=plan.compact, L=plan.L,
+                      rows=len(plan.idx), part=len(plan.part),
+                      group=len(group), explore=self._explored(mark))
+        if not group:
+            outs = self.engine.run_job(
+                plan.job, lambda: jax.block_until_ready(plan.dispatch()),
+                extra=plan.extras)
+            with trace.span("serve.commit", into=(self, "commit_s")):
+                part = list(plan.part)
+                n_new = self._commit_tick(plan, outs)
+                self._end_round(part, n_new)
+            return True, plan
+        # parallel group tick: launch every plan's jit before blocking on
+        # any (async PJRT dispatch overlaps them on the disjoint device
+        # groups), block in dispatch order, then commit.  Each pool's
+        # measured time is its elapsed-from-round-start — the overlapped
+        # reality its EMAs should price — with cold flags respected exactly
+        # as run_job would.
+        plans = [plan] + group
+        done = []
+        with trace.span(plan.job.kind, group=len(plans)):
+            t0 = time.perf_counter()
+            live = [(p, p.dispatch()) for p in plans]
+            for p, outs in live:
+                jax.block_until_ready(outs)
+                done.append((p, outs, time.perf_counter() - t0))
+        with trace.span("serve.commit", into=(self, "commit_s")):
+            part, n_new = [], 0
+            for p, outs, dt in done:
+                self.engine.observe(p.job, dt)
+                for j in p.extras:
+                    self.engine.observe(j, dt)
+                n_new += self._commit_tick(p, outs)
+                part.extend(p.part)
+            self.parallel_group_ticks += len(group)
+            self._end_round(part, n_new)
+        return True, plan
+
+    def _choose_plan(self) -> Optional[_TickPlan]:
+        """The round's composition decision and its plan (None when no pool
+        has work)."""
         spec_len = self.spec_len
         if self.single_pool:
             sp = self.pools[0]
             act = [r for r in sp.active if r is not None]
             if not act:
-                return True
+                return None
             n_pre = sum(r.prefilling for r in act)
             n_dec = len(act) - n_pre
             pre_toks = sum(len(r.prompt) - r.prompt_off
@@ -1792,49 +1895,36 @@ class ServeEngine:
         else:
             cands = self._candidates()
             if not cands:
-                return True
+                return None
             gid, mode = self.engine.choose_serve_job(cands)
             sp = self._pool(gid - self.pool_id)
             act = [r for r in sp.active if r is not None]
-        plan = self._plan_tick(sp, act, mode)
-        if plan is None:
-            return True
-        group = self._group_plans(plan)
-        if not group:
-            outs = self.engine.run_job(
-                plan.job, lambda: jax.block_until_ready(plan.dispatch()),
-                extra=plan.extras)
-            part = list(plan.part)
-            n_new = self._commit_tick(plan, outs)
-        else:
-            # parallel group tick: launch every plan's jit before blocking
-            # on any (async PJRT dispatch overlaps them on the disjoint
-            # device groups), then block in dispatch order.  Each pool's
-            # measured time is its elapsed-from-round-start — the
-            # overlapped reality its EMAs should price — with cold flags
-            # respected exactly as run_job would.
-            plans = [plan] + group
-            t0 = time.perf_counter()
-            live = [(p, p.dispatch()) for p in plans]
-            part, n_new = [], 0
-            for p, outs in live:
-                jax.block_until_ready(outs)
-                dt = time.perf_counter() - t0
-                self.engine.observe(p.job, dt)
-                for j in p.extras:
-                    self.engine.observe(j, dt)
-                n_new += self._commit_tick(p, outs)
-                part.extend(p.part)
-            self.parallel_group_ticks += len(group)
+        return self._plan_tick(sp, act, mode)
+
+    def _explored(self, mark) -> str:
+        """The decisions made since ``mark`` (the newest decision before
+        this round) that explored rather than exploited, as
+        ``decision:why`` — why a tick ran an arm its scores did not pick."""
+        out = []
+        for d in reversed(self.engine.decisions):
+            if d is mark:
+                break
+            if d.get("why") in ("bootstrap", "explore", "re-explore"):
+                out.append(f"{d['decision']}:{d['why']}")
+        return ",".join(reversed(out))
+
+    def _end_round(self, part: List[Request], n_new: int) -> None:
+        """Once per scheduling round, after its commits: aging, token and
+        tick counts, breakpoints and the autotuner hook."""
         self._age_prefills(part)
         self.tokens_out += n_new
         self._check_breakpoints(n_new)
         self.tick_no += 1
         if self.autotuner is not None:
             # meta-control at the tick boundary, work ticks only: idle
-            # ticks return above, so windows never accumulate empty time
+            # ticks return before planning, so windows never accumulate
+            # empty time
             self.autotuner.on_tick()
-        return True
 
     # ----------------------------------------------------------- convenience
     def run_until_done(self, max_ticks: int = 10_000) -> None:

@@ -63,14 +63,21 @@ def _project_qkv(cfg, p, x):
     return q, k, v
 
 
+# Device scopes: ``attention`` (projections, attention, KV-cache read and
+# write) and ``moe`` (router, dispatch, experts, combine) name the ops of
+# the two in training and serving alike, so a profile groups device time
+# by them (HLO metadata only; the compiled program is unchanged).
+
 def _self_attention(cfg, p, x, ctx, *, causal=True, window=None):
-    b, s, d = x.shape
-    q, k, v = _project_qkv(cfg, p, x)
-    q = _rope(cfg, q, ctx)
-    k = _rope(cfg, k, ctx)
-    out = attn_lib.chunked_attention(q, k, v, causal=causal, window=window)
-    out = out.reshape(b, s, cfg.n_heads * cfg.hd)
-    return jnp.einsum("bsq,qd->bsd", out, p["wo"].astype(x.dtype))
+    with jax.named_scope("attention"):
+        b, s, d = x.shape
+        q, k, v = _project_qkv(cfg, p, x)
+        q = _rope(cfg, q, ctx)
+        k = _rope(cfg, k, ctx)
+        out = attn_lib.chunked_attention(q, k, v, causal=causal,
+                                         window=window)
+        out = out.reshape(b, s, cfg.n_heads * cfg.hd)
+        return jnp.einsum("bsq,qd->bsd", out, p["wo"].astype(x.dtype))
 
 
 def _attn_block_apply(p, x, ctx, *, window=None, causal=True):
@@ -89,26 +96,39 @@ def _attn_cache(cfg: ArchConfig, batch: int, smax: int, kv_dtype=None):
             "v": z((batch, smax, kh, hd), dt)}
 
 
+def _decode_self_attention(p, x, cache, ctx, *, window=None,
+                           rolling=False):
+    """One new token's self-attention against the KV cache: pre-norm,
+    projections, the cache write at ``ctx['pos']`` and the read.  x [B,1,D];
+    cache {k,v [B,Smax,KH,hd]}.  Returns (the residual branch, new cache)."""
+    with jax.named_scope("attention"):
+        cfg, pos = ctx["cfg"], ctx["pos"]
+        xb = rms_norm(x, p["ln1"], cfg.norm_eps)
+        q, k, v = _project_qkv(cfg, p, xb)
+        q = _rope(cfg, q, ctx)
+        k = _rope(cfg, k, ctx)
+        smax = cache["k"].shape[1]
+        widx = pos % smax if rolling else pos
+        k_cache = jax.lax.dynamic_update_slice(
+            cache["k"], k.astype(cache["k"].dtype), (0, widx, 0, 0))
+        v_cache = jax.lax.dynamic_update_slice(
+            cache["v"], v.astype(cache["v"].dtype), (0, widx, 0, 0))
+        out = attn_lib.decode_attention(q, k_cache, v_cache, pos,
+                                        window=window, rolling=rolling)
+        out = out.reshape(x.shape[0], 1, cfg.n_heads * cfg.hd)
+        return (jnp.einsum("bsq,qd->bsd", out, p["wo"].astype(x.dtype)),
+                {"k": k_cache, "v": v_cache})
+
+
 def _attn_block_decode(p, x, cache, ctx, *, window=None, rolling=False):
     """x [B,1,D]; cache {k,v [B,Smax,KH,hd]}; ctx['pos'] scalar."""
-    cfg, pos = ctx["cfg"], ctx["pos"]
-    xb = rms_norm(x, p["ln1"], cfg.norm_eps)
-    q, k, v = _project_qkv(cfg, p, xb)
-    q = _rope(cfg, q, ctx)
-    k = _rope(cfg, k, ctx)
-    smax = cache["k"].shape[1]
-    widx = pos % smax if rolling else pos
-    k_cache = jax.lax.dynamic_update_slice(
-        cache["k"], k.astype(cache["k"].dtype), (0, widx, 0, 0))
-    v_cache = jax.lax.dynamic_update_slice(
-        cache["v"], v.astype(cache["v"].dtype), (0, widx, 0, 0))
-    out = attn_lib.decode_attention(q, k_cache, v_cache, pos,
-                                    window=window, rolling=rolling)
-    out = out.reshape(x.shape[0], 1, cfg.n_heads * cfg.hd)
-    h = x + jnp.einsum("bsq,qd->bsd", out, p["wo"].astype(x.dtype))
+    cfg = ctx["cfg"]
+    a, new = _decode_self_attention(p, x, cache, ctx, window=window,
+                                    rolling=rolling)
+    h = x + a
     h = h + swiglu(rms_norm(h, p["ln2"], cfg.norm_eps),
                    p["w_gate"], p["w_up"], p["w_down"])
-    return h, {"k": k_cache, "v": v_cache}
+    return h, new
 
 
 # ---------------------------------------------------------------- attn/local
@@ -152,45 +172,33 @@ def moe_defs(cfg):
             "w_down": ParamDef((s, f, d), ("experts", None, "embed"))}
 
 
+def _moe_branch(p, h, ctx):
+    """The MoE FFN's residual branch over every token of h [B,S,D]: pre-norm,
+    router, dispatch, experts, combine.  Records the layer's load metrics
+    in ``ctx['moe_metrics']``."""
+    cfg = ctx["cfg"]
+    b, s, d = h.shape
+    with jax.named_scope("moe"):
+        flat = rms_norm(h, p["ln2"], cfg.norm_eps).reshape(b * s, d)
+        y, metrics = moe_lib.moe_ffn(
+            p, flat, ctx["plan_slots"], ctx["plan_cum"], cfg,
+            token_offset=ctx.get("token_offset", 0), mesh=ctx.get("mesh"),
+            tokens_sharded=ctx.get("tokens_sharded", True),
+            layout=ctx.get("layout", "tp"))
+    ctx["moe_metrics"].append(metrics)
+    return y.reshape(b, s, d)
+
+
 def moe_apply(p, x, ctx):
     cfg = ctx["cfg"]
     h = x + _self_attention(cfg, p, rms_norm(x, p["ln1"], cfg.norm_eps), ctx)
-    b, s, d = h.shape
-    plan_slots, plan_cum = ctx["plan_slots"], ctx["plan_cum"]
-    flat = rms_norm(h, p["ln2"], cfg.norm_eps).reshape(b * s, d)
-    y, metrics = moe_lib.moe_ffn(p, flat, plan_slots, plan_cum, cfg,
-                                 token_offset=ctx.get("token_offset", 0),
-                                 mesh=ctx.get("mesh"),
-                                 tokens_sharded=ctx.get("tokens_sharded",
-                                                        True),
-                                 layout=ctx.get("layout", "tp"))
-    ctx["moe_metrics"].append(metrics)
-    return h + y.reshape(b, s, d)
+    return h + _moe_branch(p, h, ctx)
 
 
 def _moe_decode_impl(p, x, cache, ctx):
-    cfg, pos = ctx["cfg"], ctx["pos"]
-    xb = rms_norm(x, p["ln1"], cfg.norm_eps)
-    q, k, v = _project_qkv(cfg, p, xb)
-    q = _rope(cfg, q, ctx)
-    k = _rope(cfg, k, ctx)
-    k_cache = jax.lax.dynamic_update_slice(
-        cache["k"], k.astype(cache["k"].dtype), (0, pos, 0, 0))
-    v_cache = jax.lax.dynamic_update_slice(
-        cache["v"], v.astype(cache["v"].dtype), (0, pos, 0, 0))
-    out = attn_lib.decode_attention(q, k_cache, v_cache, pos)
-    out = out.reshape(x.shape[0], 1, cfg.n_heads * cfg.hd)
-    h = x + jnp.einsum("bsq,qd->bsd", out, p["wo"].astype(x.dtype))
-    b, s, d = h.shape
-    flat = rms_norm(h, p["ln2"], cfg.norm_eps).reshape(b * s, d)
-    y, metrics = moe_lib.moe_ffn(p, flat, ctx["plan_slots"], ctx["plan_cum"],
-                                 cfg, token_offset=ctx.get("token_offset", 0),
-                                 mesh=ctx.get("mesh"),
-                                 tokens_sharded=ctx.get("tokens_sharded",
-                                                        True),
-                                 layout=ctx.get("layout", "tp"))
-    ctx["moe_metrics"].append(metrics)
-    return h + y.reshape(b, s, d), {"k": k_cache, "v": v_cache}
+    a, new = _decode_self_attention(p, x, cache, ctx)
+    h = x + a
+    return h + _moe_branch(p, h, ctx), new
 
 
 # ---------------------------------------------------------------------- rwkv

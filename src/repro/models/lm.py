@@ -84,6 +84,15 @@ def _slice_leaves(tree, off: int, count: int):
 
 # ------------------------------------------------------------------- forward
 
+def _head(params, x, cfg: ArchConfig):
+    """Final norm and logits, under the ``head`` device scope (with the
+    sampler that follows in serving)."""
+    with jax.named_scope("head"):
+        x = rms_norm(x, params["final_ln"], cfg.norm_eps)
+        head = params["embed"].T if cfg.tie_embeddings else params["lm_head"]
+        return jnp.einsum("bsd,dv->bsv", x, head.astype(x.dtype))
+
+
 def _make_ctx(cfg: ArchConfig, b: int, s: int, batch: Dict[str, Any],
               impl: str, token_offset, mesh=None,
               tokens_sharded=True, layout="tp") -> Dict[str, Any]:
@@ -188,9 +197,7 @@ def forward(params, batch: Dict[str, Any], cfg: ArchConfig, *,
     if plan is None and n_moe_layers(cfg):
         plan = moe_lib.identity_plan(cfg, n_moe_layers(cfg))
     x, moe_metrics = _run_stack(x, params, cfg, ctx, plan, remat)
-    x = rms_norm(x, params["final_ln"], cfg.norm_eps)
-    head = params["embed"].T if cfg.tie_embeddings else params["lm_head"]
-    logits = jnp.einsum("bsd,dv->bsv", x, head.astype(x.dtype))
+    logits = _head(params, x, cfg)
     aux: Dict[str, Any] = {}
     if moe_metrics:
         # one stacked entry per moe run; concat over layers
@@ -263,8 +270,6 @@ def decode_step(params, state, token, cfg: ArchConfig, *, plan=None,
         caches[t] = jax.tree.map(
             lambda full, new: jax.lax.dynamic_update_slice_in_dim(
                 full, new, off, axis=0), caches[t], c_out)
-    x = rms_norm(x, params["final_ln"], cfg.norm_eps)
-    head = params["embed"].T if cfg.tie_embeddings else params["lm_head"]
-    logits = jnp.einsum("bsd,dv->bsv", x, head.astype(x.dtype))
+    logits = _head(params, x, cfg)
     return logits[:, 0].astype(jnp.float32), {
         "caches": caches, "pos": pos + 1}

@@ -17,7 +17,6 @@ continuation (§2.6.2).
 from __future__ import annotations
 
 import dataclasses
-import time
 from typing import Any, Callable, Dict, List, Optional
 
 import jax
@@ -34,6 +33,7 @@ from repro.engine.engine import Engine
 from repro.engine.jobs import Job, dispatch_kind
 from repro.models import lm
 from repro.models import moe as moe_lib
+from repro.runtime import trace
 from repro.runtime.train import (TrainHyper, build_fused_step,
                                  build_grad_step, make_state)
 
@@ -301,23 +301,46 @@ class TrainLoop:
     def run(self, steps: int) -> List[Dict[str, Any]]:
         n_mb = self.lc.microbatches
         for _ in range(steps):
+            with trace.span("train", step=True) as step_span:
+                if not self._run_step(n_mb, step_span):
+                    break
+        if self.ckpt is not None:
+            # completion barrier: every queued persist is durable (and any
+            # worker-side error re-raised here) before run() returns
+            self.ckpt.wait()
+        return self.history
+
+    def _run_step(self, n_mb: int, step_span) -> bool:
+        """One step with the control path around it; False when a control
+        message stopped the run.  Spans: ``train.control`` (before the
+        step: reading the step number, which waits for the last step's
+        expert migration, and the poll; after it: Reshape's observe/plan,
+        the migration's dispatch and the plan update) and ``train.step``
+        (batch, the engine's choices, the step through its metrics fetch),
+        whose seconds the history entry keeps as ``t_control_s`` and
+        ``t_step_s``."""
+        with trace.span("train.control") as ctl_before:
             step = int(self.state["step"])
+            step_span.set(step_num=step)
             if self._poll(step, 0):
-                break
+                return False
+        with trace.span("train.step") as step_time:
             batch = self.stream.next()
             n_tok = int(batch["tokens"].size)
             impl, (grad_mb, fused_step) = self._dispatch_impl(n_tok)
             fused_path = self._fused_eligible()
+            step_span.set(path="fused" if fused_path else "granulated",
+                          impl=impl or "config")
             extra, meta = (), None
             if impl is not None:
                 key = (impl, fused_path)
                 meta = {"cold": key not in self._impl_warm}
                 self._impl_warm.add(key)
                 if fused_path:
-                    # dispatch-impl samples come from fused-path steps only:
-                    # mixing fused- and granulated-step durations under one
-                    # dispatch_kind key would compare the impls across
-                    # different step paths, not against each other
+                    # dispatch-impl samples come from fused-path steps
+                    # only: mixing fused- and granulated-step durations
+                    # under one dispatch_kind key would compare the impls
+                    # across different step paths, not against each other
                     extra = (Job(dispatch_kind(impl, n_tok), tokens=n_tok,
                                  meta=meta),)
             if fused_path:
@@ -326,22 +349,24 @@ class TrainLoop:
                     lambda: self._step_fused(batch, n_mb, fused_step),
                     extra=extra)
             else:
-                t0 = time.perf_counter()
                 log_before = len(self.controller.log)
-                step_metrics, stopped = self._step_granulated(
-                    step, batch, n_mb, grad_mb)
+                with trace.span("train_step_granulated") as gran:
+                    step_metrics, stopped = self._step_granulated(
+                        step, batch, n_mb, grad_mb)
                 if stopped:
-                    break
+                    return False
                 if len(self.controller.log) == log_before:
                     # clean measurement only: a step that served control
-                    # messages (or sat paused) must not poison the cost model
+                    # messages (or sat paused) must not poison the cost
+                    # model
                     self.engine.observe(
                         Job("train_step_granulated", tokens=n_tok,
-                            meta=meta), time.perf_counter() - t0)
-            self.history.append({"step": step, **{
-                k: (float(v) if np.ndim(v) == 0 else v)
-                for k, v in step_metrics.items()}})
-            # ---------------- Reshape between-steps fast control path ------
+                            meta=meta), gran.seconds)
+        entry = {"step": step, **{
+            k: (float(v) if np.ndim(v) == 0 else v)
+            for k, v in step_metrics.items()}}
+        # ---------------- Reshape between-steps fast control path ----------
+        with trace.span("train.control") as ctl_after:
             if self.reshaper is not None and "expert_counts" in step_metrics:
                 self.reshaper.observe(step_metrics["expert_counts"],
                                       step_metrics.get("dropped"))
@@ -349,16 +374,15 @@ class TrainLoop:
                 if migs:
                     self._migrate(migs)
                 self._set_plan(ps, pc)
-            if self.ckpt and (step + 1) % self.lc.ckpt_every == 0:
-                self.save(step + 1)
-            if self.publish_to is not None and self.lc.publish_every and \
-                    (step + 1) % self.lc.publish_every == 0:
-                self.publish(step + 1)
-        if self.ckpt is not None:
-            # completion barrier: every queued persist is durable (and any
-            # worker-side error re-raised here) before run() returns
-            self.ckpt.wait()
-        return self.history
+        entry["t_step_s"] = step_time.seconds
+        entry["t_control_s"] = ctl_before.seconds + ctl_after.seconds
+        self.history.append(entry)
+        if self.ckpt and (step + 1) % self.lc.ckpt_every == 0:
+            self.save(step + 1)
+        if self.publish_to is not None and self.lc.publish_every and \
+                (step + 1) % self.lc.publish_every == 0:
+            self.publish(step + 1)
+        return True
 
     # -------------------------------------------------------- fault tolerance
     def save(self, step: int) -> str:

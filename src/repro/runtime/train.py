@@ -108,8 +108,9 @@ def build_train_step(cfg: ArchConfig, shape: ShapeCfg, hyper: TrainHyper,
         metrics = jax.tree.map(
             lambda m: m.sum(0) if m.dtype in (jnp.int32, jnp.int64)
             else m.mean(0), metrics)
-        params, opt, opt_metrics = adamw.apply(
-            state["params"], grads, state["opt"], hyper.opt)
+        with jax.named_scope("optimizer"):
+            params, opt, opt_metrics = adamw.apply(
+                state["params"], grads, state["opt"], hyper.opt)
         metrics.update(opt_metrics)
         new_state = {"params": params, "opt": opt, "step": state["step"] + 1}
         return new_state, metrics
@@ -171,8 +172,10 @@ def build_fused_step(cfg: ArchConfig, hyper: TrainHyper):
         (grads, _), mb_metrics = jax.lax.scan(
             mb_body, (grad_zero, jnp.zeros((), jnp.int32)), mb_batch)
         grads = jax.tree.map(lambda g: g / n_mb, grads)
-        params, opt, opt_m = adamw.apply(state["params"], grads,
-                                         state["opt"], hyper.opt, lr_scale)
+        with jax.named_scope("optimizer"):
+            params, opt, opt_m = adamw.apply(state["params"], grads,
+                                             state["opt"], hyper.opt,
+                                             lr_scale)
         new_state = {"params": params, "opt": opt,
                      "step": state["step"] + 1}
         return new_state, mb_metrics, opt_m
@@ -208,8 +211,9 @@ def build_grad_step(cfg: ArchConfig, hyper: TrainHyper, donate=None):
     @partial(jax.jit, static_argnames=("n_mb",), donate_argnums=donate_state)
     def apply(state, grads, n_mb: int, lr_scale):
         grads = jax.tree.map(lambda g: g / n_mb, grads)
-        params, opt, m = adamw.apply(state["params"], grads, state["opt"],
-                                     hyper.opt, lr_scale)
+        with jax.named_scope("optimizer"):
+            params, opt, m = adamw.apply(state["params"], grads,
+                                         state["opt"], hyper.opt, lr_scale)
         return {"params": params, "opt": opt, "step": state["step"] + 1}, m
 
     @partial(jax.jit, donate_argnums=donate_state)
