@@ -156,6 +156,7 @@ from repro.engine.jobs import (COST_DEFAULTS, Job, TickCandidate,
                                layout_kind, pool_kind, spec_kind)
 from repro.engine.prefix_cache import PrefixAnalyzer, PrefixCache, to_host
 from repro.models import lm
+from repro.models.blocks import POSITIONAL_CACHE_TYPES
 from repro.runtime import trace
 from repro.runtime.sharding import (axis_size, named, param_specs, pool_mesh,
                                     pool_specs)
@@ -172,16 +173,6 @@ def sample_traced(logits, key, temp):
 
 # xxhash/murmur-style odd multipliers, one per n-gram context position
 _NG_MULTS = (0x9E3779B1, 0x85EBCA6B, 0xC2B2AE35, 0x27D4EB2F)
-
-# cache families whose writes are position-addressed: a rejected speculative
-# step's write lands at (or rings onto) the index of the first uncommitted
-# position, which every read masks out (attention masks keys past ``pos``)
-# and which the next *accepted* token overwrites before it is ever read —
-# so these leaves need no valid-mask in the speculative scan.  Recurrent
-# and rolling-window state (rwkv's mixed states, mamba's conv window and
-# SSM state) mutates in place every step and MUST stay masked: it cannot
-# be position-rewound.
-_POSITIONAL_CACHE_TYPES = ("attn", "local", "moe", "shared_attn", "dec")
 
 
 class Proposer:
@@ -339,7 +330,7 @@ def build_slot_tick(cfg: ArchConfig, spec_len: int = 0,
         if valid is None:
             return new["caches"]
         return {
-            t: (new["caches"][t] if t in _POSITIONAL_CACHE_TYPES
+            t: (new["caches"][t] if t in POSITIONAL_CACHE_TYPES
                 else jax.tree.map(lambda o, n: jnp.where(valid, n, o),
                                   draft[t], new["caches"][t]))
             for t in draft}
@@ -401,12 +392,14 @@ def build_slot_tick(cfg: ArchConfig, spec_len: int = 0,
                 with jax.named_scope("head"):
                     nxt = jnp.argmax(logits[0], -1).astype(jnp.int32)
                 # freeze only NON-positional state past the first mismatch:
-                # KV rows a rejected step writes sit past the frozen pos —
-                # dead until the next accepted token overwrites them — but
-                # recurrent/rolling leaves cannot be position-rewound, so
-                # their rejected writes must be masked out
+                # KV rows a rejected step writes land at the frozen pos (or
+                # ring onto it), which attention masks out and the next
+                # accepted token overwrites before any read — but recurrent
+                # leaves (rwkv's mixed states, mamba's conv window and SSM
+                # state) cannot be position-rewound, so their rejected
+                # writes must be masked out
                 caches = {
-                    t: (new["caches"][t] if t in _POSITIONAL_CACHE_TYPES
+                    t: (new["caches"][t] if t in POSITIONAL_CACHE_TYPES
                         else jax.tree.map(
                             lambda o, n: jnp.where(valid, n, o),
                             caches[t], new["caches"][t]))

@@ -4,8 +4,16 @@ Block types (see configs.base): attn, local, moe, rwkv, mamba, shared_attn,
 enc, dec.  Each type defines:
   defs(cfg)                         parameter declaration (ParamDef tree)
   apply(p, x, ctx)                  full-sequence forward (train / prefill)
-  decode(p, x, cache, ctx)          one-token forward + updated cache slice
+  decode(p, x, cache, ctx)          one-token forward + updated cache
   init_cache(cfg, batch, smax)      per-layer cache pytree (ShapeDtypeStruct-able)
+
+Decode takes the cache in one of two ways, by cache type.  A positional
+cache (:data:`POSITIONAL_CACHE_TYPES`: K/V rows addressed by position) is
+handed over as the type's whole layer stack ``[n, B, S, ...]`` with the
+layer's index in ``ctx["layer"]``: the decode writes the new token's row in
+place and reads its layer where it lies, and returns the stack.  A
+recurrent state (rwkv, mamba) is handed over as the layer's own slice and
+returned rewritten whole.
 """
 from __future__ import annotations
 
@@ -100,20 +108,29 @@ def _decode_self_attention(p, x, cache, ctx, *, window=None,
                            rolling=False):
     """One new token's self-attention against the KV cache: pre-norm,
     projections, the cache write at ``ctx['pos']`` and the read.  x [B,1,D];
-    cache {k,v [B,Smax,KH,hd]}.  Returns (the residual branch, new cache)."""
+    cache {k,v [n,B,Smax,KH,hd]}, the type's layer stack, of which this is
+    layer ``ctx['layer']``.  The token's K/V row is written into the stack
+    in place and attention reads the layer where it lies, so no layer is
+    copied out or written back.  Returns (the residual branch, the
+    stack)."""
     with jax.named_scope("attention"):
-        cfg, pos = ctx["cfg"], ctx["pos"]
+        cfg, pos, layer = ctx["cfg"], ctx["pos"], ctx["layer"]
         xb = rms_norm(x, p["ln1"], cfg.norm_eps)
         q, k, v = _project_qkv(cfg, p, xb)
         q = _rope(cfg, q, ctx)
         k = _rope(cfg, k, ctx)
-        smax = cache["k"].shape[1]
+        smax = cache["k"].shape[2]
         widx = pos % smax if rolling else pos
-        k_cache = jax.lax.dynamic_update_slice(
-            cache["k"], k.astype(cache["k"].dtype), (0, widx, 0, 0))
-        v_cache = jax.lax.dynamic_update_slice(
-            cache["v"], v.astype(cache["v"].dtype), (0, widx, 0, 0))
-        out = attn_lib.decode_attention(q, k_cache, v_cache, pos,
+
+        def write(c, row):
+            return jax.lax.dynamic_update_slice(
+                c, row[None].astype(c.dtype), (layer, 0, widx, 0, 0))
+
+        def read(c):
+            return jax.lax.dynamic_index_in_dim(c, layer, keepdims=False)
+
+        k_cache, v_cache = write(cache["k"], k), write(cache["v"], v)
+        out = attn_lib.decode_attention(q, read(k_cache), read(v_cache), pos,
                                         window=window, rolling=rolling)
         out = out.reshape(x.shape[0], 1, cfg.n_heads * cfg.hd)
         return (jnp.einsum("bsq,qd->bsd", out, p["wo"].astype(x.dtype)),
@@ -121,7 +138,8 @@ def _decode_self_attention(p, x, cache, ctx, *, window=None,
 
 
 def _attn_block_decode(p, x, cache, ctx, *, window=None, rolling=False):
-    """x [B,1,D]; cache {k,v [B,Smax,KH,hd]}; ctx['pos'] scalar."""
+    """x [B,1,D]; cache {k,v [n,B,Smax,KH,hd]}; ctx['pos'], ctx['layer']
+    scalars."""
     cfg = ctx["cfg"]
     a, new = _decode_self_attention(p, x, cache, ctx, window=window,
                                     rolling=rolling)
@@ -482,29 +500,22 @@ def dec_cache(cfg, batch, smax, kv_dtype=None):
 
 
 def dec_decode(p, x, cache, ctx):
-    cfg, pos = ctx["cfg"], ctx["pos"]
-    xb = rms_norm(x, p["ln1"], cfg.norm_eps)
-    q, k, v = _project_qkv(cfg, p, xb)
-    q = _rope(cfg, q, ctx)
-    k = _rope(cfg, k, ctx)
-    k_cache = jax.lax.dynamic_update_slice(
-        cache["k"], k.astype(cache["k"].dtype), (0, pos, 0, 0))
-    v_cache = jax.lax.dynamic_update_slice(
-        cache["v"], v.astype(cache["v"].dtype), (0, pos, 0, 0))
-    out = attn_lib.decode_attention(q, k_cache, v_cache, pos)
-    out = out.reshape(x.shape[0], 1, cfg.n_heads * cfg.hd)
-    h = x + jnp.einsum("bsq,qd->bsd", out, p["wo"].astype(x.dtype))
-    # cross attention against precomputed encoder K/V
+    cfg, layer = ctx["cfg"], ctx["layer"]
+    a, new = _decode_self_attention(p, x, cache, ctx)
+    h = x + a
+    # cross attention against the precomputed encoder K/V, read in place
+    # and never rewritten
+    ck = jax.lax.dynamic_index_in_dim(cache["ck"], layer, keepdims=False)
+    cv = jax.lax.dynamic_index_in_dim(cache["cv"], layer, keepdims=False)
     xq = rms_norm(h, p["ln_c"], cfg.norm_eps)
     b = x.shape[0]
     qc = jnp.einsum("bsd,dq->bsq", xq, p["cwq"].astype(x.dtype)).reshape(
         b, 1, cfg.n_heads, cfg.hd)
-    co = attn_lib.decode_attention(qc, cache["ck"], cache["cv"],
-                                   cache["ck"].shape[1] - 1)
+    co = attn_lib.decode_attention(qc, ck, cv, ck.shape[1] - 1)
     co = co.reshape(b, 1, cfg.n_heads * cfg.hd)
     h = h + jnp.einsum("bsq,qd->bsd", co, p["cwo"].astype(x.dtype))
     h = h + _plain_mlp(p, rms_norm(h, p["ln2"], cfg.norm_eps))
-    return h, {"k": k_cache, "v": v_cache, "ck": cache["ck"], "cv": cache["cv"]}
+    return h, dict(cache, **new)
 
 
 BLOCKS: Dict[str, Dict[str, Any]] = {
@@ -525,3 +536,7 @@ BLOCKS: Dict[str, Dict[str, Any]] = {
     "dec": dict(defs=dec_defs, apply=dec_apply, decode=dec_decode,
                 cache=dec_cache),
 }
+
+# cache types whose rows are addressed by position: decode writes one row
+# in place (see the module docstring); the rest are recurrent states
+POSITIONAL_CACHE_TYPES = ("attn", "local", "moe", "shared_attn", "dec")

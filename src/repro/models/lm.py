@@ -18,7 +18,7 @@ import jax.numpy as jnp
 
 from repro.configs.base import ArchConfig
 from repro.models import moe as moe_lib
-from repro.models.blocks import BLOCKS
+from repro.models.blocks import BLOCKS, POSITIONAL_CACHE_TYPES
 from repro.models.layers import rms_norm, sinusoidal_positions
 from repro.models.params import (ParamDef, abstract_params, init_params,
                                  map_defs, param_specs, stacked)
@@ -216,9 +216,8 @@ def init_cache(cfg: ArchConfig, batch: int, smax: int, kv_dtype=None):
         mk = BLOCKS[t]["cache"]
         if mk is None:
             continue
-        one = mk(cfg, batch, smax, kv_dtype) if t in (
-            "attn", "local", "moe", "shared_attn", "dec") else mk(
-            cfg, batch, smax)
+        one = mk(cfg, batch, smax, kv_dtype) \
+            if t in POSITIONAL_CACHE_TYPES else mk(cfg, batch, smax)
         caches[t] = jax.tree.map(
             lambda x: jnp.zeros((n,) + x.shape, x.dtype), one)
     return {"caches": caches, "pos": jnp.zeros((), jnp.int32)}
@@ -241,35 +240,36 @@ def decode_step(params, state, token, cfg: ArchConfig, *, plan=None,
     caches = dict(state["caches"])
     for t, count, off in pattern_runs(cfg):
         decode = BLOCKS[t]["decode"]
-        c_run = _slice_leaves(caches[t], off, count)
-        if t == "shared_attn":
-            def body_sa(h, c_l):
-                h, c_new = decode(params[t], h, c_l, ctx)
-                return h, c_new
-            x, c_out = jax.lax.scan(body_sa, x, c_run)
-        elif t == "moe":
-            p_run = _slice_leaves(params[t], off, count)
-            ps = jax.lax.slice_in_dim(plan.slots, off, off + count)
-            pc = jax.lax.slice_in_dim(plan.cum, off, off + count)
+        # per-layer inputs: the run's weights (shared_attn has one copy,
+        # closed over) and, for moe, its slice of the routing plan
+        p_run = None if t == "shared_attn" else \
+            _slice_leaves(params[t], off, count)
+        plan_run = None
+        if t == "moe":
+            plan_run = (jax.lax.slice_in_dim(plan.slots, off, off + count),
+                        jax.lax.slice_in_dim(plan.cum, off, off + count))
 
-            def body_moe(h, inp):
-                p_l, c_l, ps_l, pc_l = inp
-                ctx_l = dict(ctx, plan_slots=ps_l, plan_cum=pc_l,
+        def step(h, c, inp):
+            p_l, plan_l, layer = inp
+            ctx_l = dict(ctx, layer=layer)
+            if plan_l is not None:
+                ctx_l.update(plan_slots=plan_l[0], plan_cum=plan_l[1],
                              moe_metrics=[])
-                h, c_new = decode(p_l, h, c_l, ctx_l)
-                return h, c_new
-            x, c_out = jax.lax.scan(body_moe, x, (p_run, c_run, ps, pc))
-        else:
-            p_run = _slice_leaves(params[t], off, count)
+            return decode(params[t] if p_l is None else p_l, h, c, ctx_l)
 
-            def body(h, inp):
-                p_l, c_l = inp
-                h, c_new = decode(p_l, h, c_l, ctx)
-                return h, c_new
-            x, c_out = jax.lax.scan(body, x, (p_run, c_run))
-        caches[t] = jax.tree.map(
-            lambda full, new: jax.lax.dynamic_update_slice_in_dim(
-                full, new, off, axis=0), caches[t], c_out)
+        xs = (p_run, plan_run, jnp.arange(off, off + count))
+        if t in POSITIONAL_CACHE_TYPES:
+            # the type's whole stack rides in the carry and each layer
+            # writes its token's row into it in place, at its own index
+            (x, caches[t]), _ = jax.lax.scan(
+                lambda hc, inp: (step(*hc, inp), None), (x, caches[t]), xs)
+        else:
+            x, c_out = jax.lax.scan(
+                lambda h, inp: step(h, inp[1], inp[0]), x,
+                (xs, _slice_leaves(caches[t], off, count)))
+            caches[t] = jax.tree.map(
+                lambda full, new: jax.lax.dynamic_update_slice_in_dim(
+                    full, new, off, axis=0), caches[t], c_out)
     logits = _head(params, x, cfg)
     return logits[:, 0].astype(jnp.float32), {
         "caches": caches, "pos": pos + 1}
