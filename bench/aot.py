@@ -67,6 +67,7 @@ def train_step(spec, shard) -> dict:
     import jax
     import jax.numpy as jnp
     from bench import model
+    from repro.models import lm
     from repro.runtime.train import abstract_state, build_fused_step
     from bench.train import hyper_for
     c, tr = spec["config"], spec["traffic"]
@@ -80,10 +81,12 @@ def train_step(spec, shard) -> dict:
     gb, s = tr["global_batch"], tr["seq_len"]
     batch = {"tokens": jax.ShapeDtypeStruct((gb, s), jnp.int32,
                                             sharding=shard)}
-    e, r = cfg.moe.num_experts, cfg.moe.max_replicas
-    nl = cfg.num_layers
-    ps = jax.ShapeDtypeStruct((nl, e, r), jnp.int32, sharding=shard)
-    pc = jax.ShapeDtypeStruct((nl, e, r), jnp.float32, sharding=shard)
+    ps = pc = None          # the routing plan, for the MoE layers only
+    if cfg.moe:
+        e, r = cfg.moe.num_experts, cfg.moe.max_replicas
+        nl = lm.n_moe_layers(cfg)
+        ps = jax.ShapeDtypeStruct((nl, e, r), jnp.int32, sharding=shard)
+        pc = jax.ShapeDtypeStruct((nl, e, r), jnp.float32, sharding=shard)
     lr = jax.ShapeDtypeStruct((), jnp.float32, sharding=shard)
     step = build_fused_step(cfg, hyper)
     compiled = step.lower(state, batch, ps, pc, lr,

@@ -6,41 +6,27 @@ token): the work the model requires, not what an implementation computes,
 so capacity padding, spare expert slots and recomputation are not counted,
 and the reading stays the same for any later kernel doing the same work.
 Attention's score and value products are added per token over the context
-that token attends to.
+that token attends to.  What a block counts (:func:`active_params`,
+:func:`attn_flops`) is its architecture module's, ``arch/<architecture>.py``;
+the sums over tokens and positions here know no block.
 """
 from __future__ import annotations
 
 from typing import Dict
 
-
-def _dims(c: Dict):
-    d, h, kh = c["hidden_size"], c["num_attention_heads"], \
-        c["num_key_value_heads"]
-    hd = c.get("head_dim") or d // h
-    return d, h, kh, hd
+from bench import model
 
 
 def active_params(c: Dict) -> int:
-    """Weights one token multiplies by: per layer the attention
-    projections, the router and top-k experts (or the dense MLP), the two
-    norms; plus the final norm and the head (the embedding lookup is not
-    a multiplication)."""
-    d, h, kh, hd = _dims(c)
-    attn = d * (h + 2 * kh) * hd + h * hd * d
-    if c.get("num_experts"):
-        ffn = d * c["num_experts"] + c["num_experts_per_tok"] * 3 * d * \
-            c["moe_intermediate_size"]
-    else:
-        ffn = 3 * d * c["intermediate_size"]
-    layer = attn + ffn + 2 * d
-    return c["num_hidden_layers"] * layer + d + d * c["vocab_size"]
+    """Weights one token multiplies by (the embedding lookup is not a
+    multiplication), by the configuration's architecture."""
+    return model.module(c).active_params(c)
 
 
 def attn_flops(c: Dict, ctx: float) -> float:
     """Forward score and value products of one query over ``ctx`` keys,
-    all layers."""
-    d, h, kh, hd = _dims(c)
-    return c["num_hidden_layers"] * 2 * 2 * ctx * h * hd
+    all layers, by the configuration's architecture."""
+    return model.module(c).attn_flops(c, ctx)
 
 
 def serve_flops(c: Dict, pos_lo: int, pos_hi: int) -> float:

@@ -1,52 +1,74 @@
 """A configuration file, turned into what the program and the reference run.
 
 ``configs/<name>.json`` holds a model's sizes under the names of its
-published ``config.json`` (``hidden_size``, ``num_experts_per_tok``, ...).
-Every configuration here is a mixture-of-experts decoder (one ``moe`` block
-type), the one architecture the reference implements.
-:func:`arch` maps them onto the program's ``ArchConfig``; :func:`layout`
-describes the weights the benchmark builds, in the program's tree, and
-:func:`make_params` builds them on the device from the seed in one jitted
-call.  The reference reads the same weights through the same layout, so
-nothing it uses is made by the program.
+published ``config.json`` (``hidden_size``, ``num_experts_per_tok``, ...),
+and names its architecture with ``"architecture": "<arch>"``: the module
+``arch/<arch>.py`` under the benchmark's root, which alone knows that
+block.  :func:`module` loads it by path and checks that it supplies the
+interface its docstring gives (``arch/moe_decoder.py``): ``arch``,
+``layout``, ``hidden``, ``active_params``, ``attn_flops`` and, for training
+cells, ``run_steps``.  A new block arrives as a new module and a
+configuration naming it, with no file here edited.
+
+:func:`arch` maps a configuration onto the program's ``ArchConfig`` and
+:func:`layout` describes the weights the benchmark builds, in the program's
+tree, both by the architecture's module; :func:`make_params` builds them on
+the device from the seed in one jitted call.  The reference reads the same
+weights through the same layout, so nothing it uses is made by the program.
 """
 from __future__ import annotations
 
+import importlib.util
 import math
+import re
+from pathlib import Path
 from typing import Any, Dict, Tuple
 
 import jax
 import jax.numpy as jnp
 
+from bench import spec
+
 DTYPES = {"bfloat16": jnp.bfloat16, "float32": jnp.float32}
+INTERFACE = ("arch", "layout", "hidden", "active_params", "attn_flops")
+_MODULES: Dict[Path, Any] = {}
 
 
-def n_slots(c: Dict) -> int:
-    """Physical expert slots: the experts plus the program's spare slots
-    (Reshape's helpers), which the serving plan leaves unused."""
-    return c["num_experts"] + c["program"]["spare_slots"]
+def module(c: Dict):
+    """The module of configuration ``c``'s architecture,
+    ``arch/<architecture>.py`` under the benchmark's root (``spec.ROOT``,
+    read at each call), loaded once a process, so that its jitted
+    functions compile once, and checked against the interface.
+    Fails, naming the configuration and the file, where the key or the
+    file is missing; nothing is picked by default."""
+    name = c.get("architecture")
+    if not isinstance(name, str) or not re.fullmatch(r"\w+", name):
+        raise SystemExit(
+            f"configuration {c.get('name')!r} names no architecture: give "
+            f"it \"architecture\": \"<arch>\" for a module "
+            f"{spec.BENCH.name}/arch/<arch>.py (have {name!r})")
+    path = spec.ROOT / spec.BENCH.name / "arch" / f"{name}.py"
+    if path not in _MODULES:
+        if not path.is_file():
+            raise SystemExit(
+                f"configuration {c.get('name')!r} names architecture "
+                f"{name!r}, but {path} does not exist")
+        sp = importlib.util.spec_from_file_location(f"bench_arch_{name}",
+                                                    path)
+        mod = importlib.util.module_from_spec(sp)
+        sp.loader.exec_module(mod)
+        missing = [f for f in INTERFACE if not callable(getattr(mod, f,
+                                                                 None))]
+        if missing:
+            raise SystemExit(f"{path} lacks {', '.join(missing)} of the "
+                             f"architecture interface")
+        _MODULES[path] = mod
+    return _MODULES[path]
 
 
 def arch(c: Dict):
     """The program's ``ArchConfig`` for configuration ``c``."""
-    from repro.configs.base import ArchConfig, MoECfg
-    prog = c["program"]
-    moe = MoECfg(num_experts=c["num_experts"],
-                 top_k=c["num_experts_per_tok"],
-                 expert_d_ff=c["moe_intermediate_size"],
-                 capacity_factor=prog["capacity_factor"],
-                 spare_slots=prog["spare_slots"],
-                 max_replicas=prog["max_replicas"])
-    return ArchConfig(
-        name=c["name"], family="moe",
-        num_layers=c["num_hidden_layers"], d_model=c["hidden_size"],
-        n_heads=c["num_attention_heads"],
-        n_kv_heads=c["num_key_value_heads"],
-        d_ff=c["intermediate_size"], vocab=c["vocab_size"],
-        head_dim=c.get("head_dim", 0), moe=moe,
-        rope_theta=c["rope_theta"],
-        tie_embeddings=c["tie_word_embeddings"],
-        norm_eps=c["rms_norm_eps"], source=c["source"])
+    return module(c).arch(c)
 
 
 Leaf = Tuple[Tuple[int, ...], str, int]     # shape, init law, fan-in
@@ -55,24 +77,7 @@ Leaf = Tuple[Tuple[int, ...], str, int]     # shape, init law, fan-in
 def layout(c: Dict) -> Dict[str, Any]:
     """{name: (shape, init, fan_in)} in the program's parameter tree.
     Init laws: ``embed`` N(0, 0.02), ``ones``, ``normal`` N(0, 1/fan_in)."""
-    d, v, n = c["hidden_size"], c["vocab_size"], c["num_hidden_layers"]
-    h, kh = c["num_attention_heads"], c["num_key_value_heads"]
-    hd = c.get("head_dim") or d // h
-    tree: Dict[str, Any] = {"embed": ((v, d), "embed", d),
-                            "final_ln": ((d,), "ones", d)}
-    if not c["tie_word_embeddings"]:
-        tree["lm_head"] = ((d, v), "normal", d)
-    s, f = n_slots(c), c["moe_intermediate_size"]
-    tree["moe"] = {"ln1": ((n, d), "ones", d), "ln2": ((n, d), "ones", d),
-                   "wq": ((n, d, h * hd), "normal", d),
-                   "wk": ((n, d, kh * hd), "normal", d),
-                   "wv": ((n, d, kh * hd), "normal", d),
-                   "wo": ((n, h * hd, d), "normal", h * hd),
-                   "router": ((n, d, c["num_experts"]), "normal", d),
-                   "w_gate": ((n, s, d, f), "normal", d),
-                   "w_up": ((n, s, d, f), "normal", d),
-                   "w_down": ((n, s, f, d), "normal", f)}
-    return tree
+    return module(c).layout(c)
 
 
 def _is_leaf(x) -> bool:

@@ -6,18 +6,22 @@ through the benchmark's own layout.  Matrix products run at ``highest``
 precision.  It follows the program's architecture where that departs from
 the published model (the configuration file lists each departure).
 
+What knows the block lives in the architecture's module,
+``arch/<architecture>.py`` (``model.module``): its ``hidden`` is the forward
+pass to the final hidden state, built from the pieces here that know no
+block (:func:`quantize`, :func:`act`, :func:`mm`, :func:`rms_norm`,
+:func:`rope`).  This file keeps those, the head and the comparison.
+
 Serving (:func:`serve_gaps`): one causal forward pass over each sampled
-prompt with its served tokens, every expert computed for every token and
-weighted by the renormalised top-k router probabilities (no capacity, no
-drops: one token at a time never overflows an expert).  For each served
-token it reads how far that token's logit lies below the reference's best.
+prompt with its served tokens, then the head.  For each served token it
+reads how far that token's logit lies below the reference's best.
 
 The control (``quant="fp8"``) is the same pass with both operands of every
 matrix product with a weight rounded to float8 e4m3 (one scale per output
 column of the weight, one per row of the activation): the precision step
 below the configuration's bfloat16 compute that a later change might be
-tempted to take.  It is read at the same prompts and tokens: the gap of the token it
-would put first.
+tempted to take.  It is read at the same prompts and tokens: the gap of the
+token it would put first.
 """
 from __future__ import annotations
 
@@ -27,6 +31,8 @@ from typing import Dict, List, Sequence, Tuple
 import jax
 import jax.numpy as jnp
 import numpy as np
+
+from bench import model
 
 F32 = jnp.float32
 
@@ -78,63 +84,6 @@ def rope(x, theta):
     return jnp.concatenate([x1 * cos - x2 * sin, x1 * sin + x2 * cos], -1)
 
 
-def attention(c, lp, h, quant):
-    b, s, d = h.shape
-    nh, kh = c["num_attention_heads"], c["num_key_value_heads"]
-    hd = c.get("head_dim") or d // nh
-    q = mm(h, lp["wq"], quant).reshape(b, s, nh, hd)
-    k = mm(h, lp["wk"], quant).reshape(b, s, kh, hd)
-    v = mm(h, lp["wv"], quant).reshape(b, s, kh, hd)
-    q, k = rope(q, c["rope_theta"]), rope(k, c["rope_theta"])
-    rep = nh // kh
-    k, v = jnp.repeat(k, rep, axis=2), jnp.repeat(v, rep, axis=2)
-    sc = jnp.einsum("bqhd,bkhd->bhqk", q, k) / jnp.sqrt(F32(hd))
-    mask = jnp.tril(jnp.ones((s, s), bool))
-    sc = jnp.where(mask[None, None], sc, -jnp.inf)
-    o = jnp.einsum("bhqk,bkhd->bqhd", jax.nn.softmax(sc, -1), v)
-    return mm(o.reshape(b, s, nh * hd), lp["wo"], quant)
-
-
-def router(c, lp, h, quant):
-    """Top-k experts and their renormalised weights, as a dense [T, E]
-    gate (zero off the top k)."""
-    logits = mm(h, lp["router"], quant)
-    probs = jax.nn.softmax(logits, -1)
-    top, idx = jax.lax.top_k(probs, c["num_experts_per_tok"])
-    w = top / jnp.sum(top, -1, keepdims=True)
-    gate = jnp.zeros_like(probs)
-    rows = jnp.arange(probs.shape[0])[:, None]
-    return gate.at[rows, idx].set(w), probs
-
-
-def experts_dense(c, lp, h, gate, quant):
-    """sum_e gate[:, e] * expert_e(h), one expert at a time."""
-    e = c["num_experts"]
-
-    def one(acc, xs):
-        wg, wu, wd, g = xs
-        y = mm(jax.nn.silu(mm(h, wg, quant)) * mm(h, wu, quant), wd, quant)
-        return acc + g[:, None] * y, None
-
-    y, _ = jax.lax.scan(one, jnp.zeros_like(h),
-                        (lp["w_gate"][:e], lp["w_up"][:e], lp["w_down"][:e],
-                         gate.T))
-    return y
-
-
-@partial(jax.jit, static_argnames=("cj", "quant"))
-def _layer(x, lp, cj, quant):
-    c = dict(cj)
-    eps = c["rms_norm_eps"]
-    with jax.default_matmul_precision("highest"):
-        x = x + attention(c, lp, rms_norm(x, lp["ln1"].astype(F32), eps),
-                          quant)
-        b, s, d = x.shape
-        h = rms_norm(x, lp["ln2"].astype(F32), eps).reshape(b * s, d)
-        gate, _ = router(c, lp, h, quant)
-        return x + experts_dense(c, lp, h, gate, quant).reshape(b, s, d)
-
-
 @partial(jax.jit, static_argnames=("cj", "quant"))
 def _head(x, final_ln, head, cj, quant):
     c = dict(cj)
@@ -143,23 +92,11 @@ def _head(x, final_ln, head, cj, quant):
         return mm(h, head, quant)
 
 
-def _frozen(c: Dict) -> Tuple:
-    keys = ("num_attention_heads", "num_key_value_heads", "head_dim",
-            "hidden_size", "rope_theta", "rms_norm_eps", "num_experts",
-            "num_experts_per_tok")
-    return tuple((k, c.get(k)) for k in keys)
-
-
 def hidden(c: Dict, params, tokens: np.ndarray, quant=None):
-    """Final hidden states [B, S, D] for padded token rows [B, S]; padding
-    must trail each row (causal attention keeps it out of real rows)."""
-    cj = _frozen(c)
-    x = jnp.asarray(params["embed"])[jnp.asarray(tokens)].astype(F32)
-    blk = params["moe"]
-    for layer in range(c["num_hidden_layers"]):
-        lp = {k: v[layer] for k, v in blk.items()}
-        x = _layer(x, lp, cj, quant)
-    return x
+    """Final hidden states [B, S, D] for padded token rows [B, S], by the
+    configuration's architecture (``model.module``); padding must trail
+    each row."""
+    return model.module(c).hidden(c, params, tokens, quant)
 
 
 def _head_w(c, params):
@@ -179,7 +116,7 @@ def serve_gaps(c: Dict, params, seqs: Sequence[Tuple[np.ndarray, np.ndarray]],
         rows[i, :len(full)] = full
     h_ref = hidden(c, params, rows)
     h_ctl = hidden(c, params, rows, quant) if quant else None
-    cj = _frozen(c)
+    cj = (("rms_norm_eps", c["rms_norm_eps"]),)
     head = _head_w(c, params)
     out = []
     for i, (p, o) in enumerate(seqs):

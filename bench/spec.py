@@ -3,9 +3,11 @@
 A cell (``workloads`` entry) names a configuration (``configs/<name>.json``
 by the ``file`` of its ``configs`` entry) and a traffic mix
 (``traffic/<mix>.json``); its correctness limits are ``limits/<cell>.json``;
-each metric is read by ``metrics/<metric>.py``.  Nothing here knows any
-cell, mix or metric by name: a later change adds one by adding files and
-entries.
+each metric is read by ``metrics/<metric>.py``; the configuration's
+``"architecture"`` names the module ``arch/<architecture>.py`` that knows
+its block (``model.module`` loads it and gives the interface it must
+supply).  Nothing here knows any cell, mix, metric or architecture by name:
+a later change adds one by adding files and entries.
 """
 from __future__ import annotations
 

@@ -113,3 +113,15 @@ def test_a_cell_and_a_metric_are_added_by_files_alone(tmp_path):
     from bench import gen
     reqs = gen.serve_requests(cell["traffic"], 50304, 5, "window", 10.0)
     assert all(len(r.prompt) >= 512 + 64 for r in reqs)
+
+
+def test_every_config_names_an_architecture_file():
+    """Each configuration's ``architecture`` is a module under ``arch/``
+    that loads and supplies the interface."""
+    from bench import model
+    for c in B["configs"]:
+        conf = json.loads((spec.ROOT / c["file"]).read_text())
+        path = spec.BENCH / "arch" / f"{conf['architecture']}.py"
+        assert path.is_file(), (c["name"], path)
+        mod = model.module(conf)
+        assert all(callable(getattr(mod, f)) for f in model.INTERFACE)
